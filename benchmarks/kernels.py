@@ -19,7 +19,7 @@ from benchmarks.common import emit, timeit
 from repro.core import measures
 from repro.core.allpairs import allpairs, prepare
 from repro.core.api import corr
-from repro.core.plan import ExecutionPlan
+from repro.core.plan import ExecutionPlan, resolve_interpret
 from repro.core.quantize import fp8_dtype, quantize_rows
 from repro.core.sinks import EdgeCountSink, HostSink, TopKSink
 from repro.kernels.flash_attention import grid_savings
@@ -53,6 +53,7 @@ def epilogue_hbm_bytes(pass_tiles: int, t: int, fused: bool,
 
 
 def run() -> None:
+    interpret = resolve_interpret(None)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((256, 128)).astype(np.float32))
 
@@ -68,7 +69,7 @@ def run() -> None:
     u, plan = prepare(x[:64, :64], t=16, l_blk=32)
     t_int = timeit(lambda: pcc_tiles(u, 0, t=16, l_blk=32,
                                      pass_tiles=plan.total_tiles,
-                                     interpret=True), warmup=1, iters=1)
+                                     interpret=interpret), warmup=1, iters=1)
     emit("kernels/pcc_interpret_t16", t_int * 1e6,
          f"tiles={plan.total_tiles}")
 
@@ -86,8 +87,8 @@ def run() -> None:
     xe = x[:64, :]
     for fused in (True, False):
         t_e = timeit(lambda fused=fused: corr(
-            xe, t=16, l_blk=32, measure="covariance", fuse_epilogue=fused,
-            interpret=True), warmup=1, iters=1)
+            xe, t=16, l_blk=32, measure="covariance", fuse_epilogue=fused),
+            warmup=1, iters=1)
         label = "fused" if fused else "unfused"
         emit(f"kernels/pcc_epilogue_{label}", t_e * 1e6,
              f"hbm_bytes_per_pass="
@@ -99,7 +100,7 @@ def run() -> None:
     for dname, ud in [("f32", u32), ("bf16", u32.astype(jnp.bfloat16))]:
         t_d = timeit(lambda ud=ud: pcc_tiles(ud, 0, t=16, l_blk=32,
                                              pass_tiles=plan32.total_tiles,
-                                             interpret=True),
+                                             interpret=interpret),
                      warmup=1, iters=1)
         emit(f"kernels/pcc_interpret_dtype_{dname}", t_d * 1e6,
              f"operand_bytes={ud.size * ud.dtype.itemsize}")
@@ -107,7 +108,7 @@ def run() -> None:
                         compute_dtype=jnp.int8)
     t_8 = timeit(lambda: pcc_tiles(u8, 0, t=16, l_blk=32,
                                    pass_tiles=plan8.total_tiles,
-                                   interpret=True), warmup=1, iters=1)
+                                   interpret=interpret), warmup=1, iters=1)
     emit("kernels/pcc_interpret_dtype_int8_kendall", t_8 * 1e6,
          f"operand_bytes={u8.size * u8.dtype.itemsize};"
          f"pairs={24 * 23 // 2}")
@@ -154,7 +155,7 @@ def run() -> None:
                       ("edgecount", lambda: EdgeCountSink(0.2))]:
         t_s = timeit(lambda mk=mk: allpairs(xs, t=16, l_blk=32,
                                             max_tiles_per_pass=4,
-                                            sink=mk(), interpret=True),
+                                            sink=mk()),
                      warmup=1, iters=1)
         emit(f"kernels/executor_sink_{label}", t_s * 1e6,
              "n=64;l=64;t=16;mtp=4")
@@ -164,7 +165,7 @@ def run() -> None:
     # workaround (embedding X and Y in one (n_r+n_c)^2 triangle): the grid
     # computes exactly m_r*m_c tiles.
     xq, yq = x[:48, :64], x[64:192, :64]
-    t_rect = timeit(lambda: corr(xq, yq, t=16, l_blk=32, interpret=True),
+    t_rect = timeit(lambda: corr(xq, yq, t=16, l_blk=32),
                     warmup=1, iters=1)
     mr, mc = 48 // 16, 128 // 16
     embed = (mr + mc) * (mr + mc + 1) // 2
@@ -181,7 +182,7 @@ def run() -> None:
     xnj = jnp.asarray(xn)
     for name, ncomp in [("pearson", 6), ("cosine", 3)]:
         t_m = timeit(lambda name=name: corr(xnj, where="nan", measure=name,
-                                            t=16, l_blk=32, interpret=True),
+                                            t=16, l_blk=32),
                      warmup=1, iters=1)
         emit(f"kernels/masked_{name}_interpret", t_m * 1e6,
              f"n=48;l=64;nan_frac=0.3;component_gemms={ncomp};"
@@ -189,8 +190,8 @@ def run() -> None:
 
     # top-k sink: O(n*k) streaming state vs the dense matrix
     t_k = timeit(lambda: corr(x[:64, :64], t=16, l_blk=32,
-                              max_tiles_per_pass=4, sink=TopKSink(8),
-                              interpret=True), warmup=1, iters=1)
+                              max_tiles_per_pass=4, sink=TopKSink(8)),
+                 warmup=1, iters=1)
     emit("kernels/executor_sink_topk", t_k * 1e6,
          f"n=64;k=8;state_bytes={64 * 8 * (4 + 8)}")
 

@@ -34,6 +34,8 @@ def main() -> None:
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import common
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
 
     def want(name: str) -> bool:

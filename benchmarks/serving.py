@@ -48,8 +48,7 @@ def run() -> None:
         rng.standard_normal((N_CORPUS, L)).astype(np.float32))
     handle = CorpusHandle(corpus, t=T, l_blk=LBLK)
     cache = PlanCache()
-    bat = QueryBatcher(handle, t=T, l_blk=LBLK, plan_cache=cache,
-                       interpret=True)
+    bat = QueryBatcher(handle, t=T, l_blk=LBLK, plan_cache=cache)
     probes = [jnp.asarray(rng.standard_normal((m, L)).astype(np.float32))
               for m in (5, 7, 3)]
 
@@ -77,7 +76,7 @@ def run() -> None:
 
     def serial():
         for p in singles:
-            np.asarray(corr(p, corpus, t=T, l_blk=LBLK, interpret=True))
+            np.asarray(corr(p, corpus, t=T, l_blk=LBLK))
 
     def batched():
         bat.execute(queries)
@@ -103,9 +102,9 @@ def run() -> None:
     api.clear_prepared_cache()
     xs = jnp.asarray(rng.standard_normal((48, L)).astype(np.float32))
     t_cold = timeit_host(lambda: np.asarray(
-        corr(xs, t=T, l_blk=LBLK, interpret=True)))
+        corr(xs, t=T, l_blk=LBLK)))
     t_warm = timeit_host(lambda: np.asarray(
-        corr(xs, t=T, l_blk=LBLK, interpret=True)))
+        corr(xs, t=T, l_blk=LBLK)))
     st = api.prepared_cache_stats()
     emit("serving/corr_repeat_cold", t_cold * 1e6,
          f"n=48;l={L};transforms={st['misses']}")

@@ -64,7 +64,7 @@ def run() -> None:
     x = jnp.asarray(rng.standard_normal((N, L)).astype(np.float32))
     spec = PermutationSpec(iterations=B, key=jax.random.PRNGKey(5),
                            chunk=CHUNK)
-    kw = dict(t=T, l_blk=LBLK, interpret=True)
+    kw = dict(t=T, l_blk=LBLK)
 
     def engine():
         r, p = corr(x, pvalues=spec, **kw)
@@ -97,7 +97,7 @@ def run() -> None:
     from repro.serving import CorpusHandle, CorrServer
     handle = CorpusHandle(x, t=T, l_blk=LBLK)
     probes = jnp.asarray(rng.standard_normal((4, L)).astype(np.float32))
-    with CorrServer(handle, t=T, l_blk=LBLK, interpret=True) as srv:
+    with CorrServer(handle, t=T, l_blk=LBLK) as srv:
         t_cold = timeit_host(
             lambda: srv.significance(probes, pvalues=spec))
         res = srv.significance(probes, pvalues=spec)

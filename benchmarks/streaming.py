@@ -50,8 +50,8 @@ def run() -> None:
     # -- append: incremental maintain + delta plans vs cold rebuild ---------
     cache = PlanCache()
     handles = [CorpusHandle(x0, t=T, l_blk=LBLK) for _ in range(STEPS + 1)]
-    indexes = [LiveIndex(h, measure="pearson", plan_cache=cache,
-                         interpret=True) for h in handles]
+    indexes = [LiveIndex(h, measure="pearson", plan_cache=cache)
+               for h in handles]
     tiles = {"n": 0}
     orig = allpairs.launch_tiles
 
@@ -74,7 +74,7 @@ def run() -> None:
     def cold_rebuild():
         u = prepare_operand_raw(jnp.asarray(full), meas, None, T, LBLK)
         jnp.asarray(u).block_until_ready()
-        np.asarray(corr(full, t=T, l_blk=LBLK, interpret=True))
+        np.asarray(corr(full, t=T, l_blk=LBLK))
 
     cold_rebuild()                  # warm-up: same discipline
     t_cold = timeit_host(cold_rebuild, iters=STEPS)
@@ -94,7 +94,7 @@ def run() -> None:
     probes = rng.standard_normal((4, L)).astype(np.float32)
     wcache = PlanCache()
     servers = [CorrServer(x0, t=T, l_blk=LBLK, max_wait_s=0.0,
-                          plan_cache=wcache, interpret=True)
+                          plan_cache=wcache)
                for _ in range(STEPS + 1)]
     try:
         watches = [srv.watch(probes, 5) for srv in servers]
@@ -103,7 +103,7 @@ def run() -> None:
             lambda: [srv.corpus.append(d) for srv in servers[1:]]) / STEPS
 
         def full_requery():
-            np.asarray(corr(probes, full, t=T, l_blk=LBLK, interpret=True))
+            np.asarray(corr(probes, full, t=T, l_blk=LBLK))
 
         full_requery()
         t_full = timeit_host(full_requery, iters=STEPS)

@@ -59,10 +59,8 @@ def main() -> None:
 
     pushes = []
 
-    with CorrServer(x, t=T, l_blk=LBLK, max_wait_s=0.0,
-                    interpret=True) as srv, \
-            LiveIndex(srv.corpus, measure="pearson", k=args.k,
-                      interpret=True) as index:
+    with CorrServer(x, t=T, l_blk=LBLK, max_wait_s=0.0) as srv, \
+            LiveIndex(srv.corpus, measure="pearson", k=args.k) as index:
         watch = srv.watch(probes, args.k,
                           callback=lambda snap: pushes.append(snap))
 
@@ -80,14 +78,12 @@ def main() -> None:
             x[np.sort(idx)] = repl[np.argsort(idx)]
 
             # -- both standing consumers must match a cold recompute -------
-            cold = corr(x, t=T, l_blk=LBLK, interpret=True,
-                        sink=TopKSink(args.k))
+            cold = corr(x, t=T, l_blk=LBLK, sink=TopKSink(args.k))
             live = index.result()
             err_i = check_topk(f"index step {step}", live["indices"],
                                live["values"], cold, args.k)
 
-            cold_w = corr(probes, x, t=T, l_blk=LBLK, interpret=True,
-                          sink=TopKSink(args.k))
+            cold_w = corr(probes, x, t=T, l_blk=LBLK, sink=TopKSink(args.k))
             snap = watch.current()
             err_w = check_topk(f"watch step {step}", snap["indices"],
                                snap["values"], cold_w, args.k)

@@ -44,10 +44,10 @@ from typing import Iterator, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import mapping, measures, tiling
 from repro.core.plan import (ExecutionPlan, pad_operands, resolve_interpret,
                              tiles_per_device)
@@ -143,9 +143,10 @@ def launch_topk_tiles(plan: ExecutionPlan, u, j0, dev_hi, launch: int,
     """Launch seam of the device-side top-k epilogue
     (kernels/pcc_tile.pcc_topk_tiles): one pass's tiles are computed and
     folded into per-row top-k state entirely in VMEM, so only O(n * kk)
-    state crosses to the host.  j0 is the *raw* (unclamped) device-local
-    global start and dev_hi the device's exclusive bound — the kernel's
-    validity guard, which replaces the executor's clamped-slot filtering.
+    state (plus, on the triangle, O(kk * t) per tile slot) crosses to the
+    host.  j0 is the *raw* (unclamped) device-local global start and dev_hi
+    the device's exclusive bound — the kernel's validity guard, which
+    replaces the executor's clamped-slot filtering.
     """
     u_data, u_scale = operand_parts(u)
     v_data, _ = operand_parts(v) if v is not None else (None, None)
@@ -161,6 +162,13 @@ def launch_topk_tiles(plan: ExecutionPlan, u, j0, dev_hi, launch: int,
                           interpret=plan.interpret,
                           epilogue=plan.epilogue_spec,
                           v_pad=v_data, grid_cols=grid_cols)
+
+
+def _with_slot_ids(state, slot_ids: np.ndarray):
+    """Append the clamped tile id of every slot to a triangular top-k state
+    tuple: the per-slot column-side states (kernels/pcc_tile.pcc_topk_tiles)
+    name no rows of their own, and the sink places them by these ids."""
+    return state if len(state) == 2 else (*state, slot_ids)
 
 
 def _local_launches(plan: ExecutionPlan, u_pad: Array,
@@ -184,8 +192,8 @@ def _local_launches(plan: ExecutionPlan, u_pad: Array,
             buf = launch_topk_tiles(plan, u_pad, lo, plan.total_tiles,
                                     launch, state_k, v=v_pad,
                                     grid_cols=grid_cols)
-            yield k, np.arange(lo, lo + launch, dtype=np.int64), buf, \
-                None, None
+            ids = np.arange(lo, lo + launch, dtype=np.int64)
+            yield k, ids, _with_slot_ids(buf, ids), None, None
             continue
         buf = launch_tiles(plan, u_pad, lo, launch, v=v_pad,
                            grid_cols=grid_cols)
@@ -309,7 +317,7 @@ def _mesh_launches(plan: ExecutionPlan, u_pad: Array, mesh: Mesh,
                  + ((P(None), P(None)) if has_s else ())
                  + (P(None),))
         if state_k is not None:
-            # 2 state stacks for grids, 4 (row + mirrored col) for triangles
+            # 2 state stacks for grids, 4 (row + per-slot col) for triangles
             n_out = 4 if grid_cols is None else 2
             out_spec = tuple(P(axes) for _ in range(n_out))
         else:
@@ -331,7 +339,8 @@ def _mesh_launches(plan: ExecutionPlan, u_pad: Array, mesh: Mesh,
         if state_k is not None:
             # state stacks carry their own validity guard: no clamped-slot
             # selection to resolve, and ids are the pass's true tile set
-            yield k, plan.pass_selection(k)[0], buf, None, None
+            yield (k, plan.pass_selection(k)[0],
+                   _with_slot_ids(buf, plan.pass_padded_ids(k)), None, None)
             continue
         if not plan.fused and plan.measure.epilogue is not None:
             buf = plan.measure.epilogue(buf, plan.l)

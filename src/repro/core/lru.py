@@ -30,7 +30,10 @@ class LruStatsCache:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = int(capacity)
         self._entries: OrderedDict = OrderedDict()
-        self._lock = threading.Lock()
+        # re-entrant: a weakref death callback (_evict) can run inside a
+        # locked section on the same thread, whenever an allocation there
+        # triggers a garbage collection — a plain Lock deadlocks on it
+        self._lock = threading.RLock()
         self.hits = 0
         self.misses = 0
 

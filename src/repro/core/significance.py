@@ -55,10 +55,10 @@ from typing import Callable, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import measures, quantize
 from repro.core.plan import ExecutionPlan, needs_row_scales
 from repro.core.quantize import Operand, operand_parts

@@ -37,6 +37,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import mapping
 from repro.core.plan import ExecutionPlan
@@ -101,9 +102,10 @@ class TileSink(abc.ABC):
         every slot's clamped id, duplicates carrying identical content.
 
         The default transfers to host and filters there — never a device
-        gather, so per-device memory stays bounded by the pass buffer the
-        kernel already wrote.  DenseSink overrides this to scatter the raw
-        buffer with the clamped ids instead (duplicates are idempotent).
+        gather of the valid slots, so per-device memory stays bounded by the
+        pass buffer the kernel already wrote.  DenseSink overrides this to
+        scatter the raw buffer with the clamped ids instead (duplicates are
+        idempotent).
         """
         del padded_ids
         self.consume(ids, np.asarray(tiles)[sel])
@@ -129,13 +131,34 @@ def _scatter_tiles_device(r_pad: Array, tiles: Array, coords: Array) -> Array:
 _scatter_tiles_device = jax.jit(_scatter_tiles_device)
 
 
+def output_sharding(tiles) -> Optional[NamedSharding]:
+    """Where a dense output assembled from `tiles` lives: replicated over
+    the mesh of mesh-sharded tiles, else None (the default device).
+
+    Every device of the mesh then holds the whole padded matrix, and each
+    pass's (p * launch, t, t) buffer is all-gathered before the scatter —
+    the placement XLA picks for a scatter of batch-sharded updates anyway
+    (a row-sharded output would all-gather the matrix itself), now stated
+    so that meshes with Explicit axes (``jax.make_mesh``'s default) accept
+    it and no run funnels every shard onto one device."""
+    sharding = getattr(tiles, "sharding", None)
+    if isinstance(sharding, NamedSharding):
+        return NamedSharding(sharding.mesh, PartitionSpec())
+    return None
+
+
 def scatter_tiles_at(r_pad: Array, tiles: Array, ys: np.ndarray,
                      xs: np.ndarray, t: int) -> Array:
     """Scatter (t, t) tiles into r_pad at tile coordinates (ys, xs) via one
     batched device scatter.  Workload-agnostic: callers invert ids with
-    whichever bijection numbers their jobs."""
+    whichever bijection numbers their jobs.  Mesh-sharded tiles land in a
+    mesh-replicated r_pad (see output_sharding)."""
     coords = jnp.stack([jnp.asarray(ys * t, jnp.int32),
                         jnp.asarray(xs * t, jnp.int32)], axis=1)
+    placement = output_sharding(tiles)
+    if placement is not None:
+        r_pad, tiles, coords = jax.device_put((r_pad, tiles, coords),
+                                              placement)
     return _scatter_tiles_device(r_pad, tiles.astype(r_pad.dtype), coords)
 
 
@@ -190,9 +213,12 @@ class DenseSink(TileSink):
 
     def open(self, plan: ExecutionPlan) -> None:
         super().open(plan)
-        self.r_pad = jnp.zeros((plan.n_pad, plan.col_pad), jnp.float32)
+        self.r_pad = None  # allocated where the first pass's tiles live
 
     def _scatter(self, ids: np.ndarray, tiles: Array) -> None:
+        if self.r_pad is None:
+            self.r_pad = jnp.zeros((self.plan.n_pad, self.plan.col_pad),
+                                   jnp.float32, device=output_sharding(tiles))
         ys, xs = self.plan.workload.job_coord_batch(np.asarray(ids))
         self.r_pad = scatter_tiles_at(self.r_pad, tiles, ys, xs, self.plan.t)
 
@@ -203,8 +229,9 @@ class DenseSink(TileSink):
                         ids: np.ndarray, tiles: Array) -> None:
         # Scatter the raw sharded buffer with the clamped ids: duplicate
         # slots hold identical tiles (the kernel clamps the same way), so
-        # the write set equals the valid set — no cross-device gather, and
-        # bit-identical to the historical clamped-id assembly.
+        # the write set equals the valid set — no selection of valid slots
+        # on device, and bit-identical to the historical clamped-id
+        # assembly.
         del sel, ids
         self._scatter(padded_ids, tiles)
 
@@ -1311,23 +1338,27 @@ class DeviceTopKSink(TopKSink):
                 "merge; use TopKSink")
 
     def consume(self, ids: np.ndarray, state) -> None:
-        """One pass's state stacks: (row_vals, row_cols[, col_vals,
-        col_cols]), each (D * m, t, kk) with D devices' states stacked
-        (D == 1 for local runs).  `ids` is the pass's valid tile set —
-        unused for content (the kernel's validity guard already excluded
-        clamped slots) but part of the coverage contract."""
+        """One pass's state stacks: (row_vals, row_cols) each (D * m, t, kk)
+        with D devices' states stacked (D == 1 for local runs), plus for
+        triangular runs (col_vals, col_cols) each (D * launch, t, kk) — one
+        column-side state per tile slot — and the slots' clamped tile ids.
+        `ids` is the pass's valid tile set — unused for content (the
+        kernel's validity guard already excluded clamped slots) but part of
+        the coverage contract."""
         del ids
         plan = self.plan
         t, n_r = plan.t, plan.n_rows
         m = plan.n_pad // t
-        pairs = [(state[0], state[1])]
+        # slab j of each device's m-block row state is global row block j % m
+        row_blocks = np.arange(np.shape(state[0])[0]) % m
+        stacks = [(state[0], state[1], row_blocks)]
         if len(state) > 2:
-            pairs.append((state[2], state[3]))
-        for sv, sc in pairs:
-            sv = np.asarray(sv).reshape(-1, t, sv.shape[-1])
-            sc = np.asarray(sc).reshape(sv.shape)
-            # slab j of each device's m-block state is global row block j % m
-            blocks = np.arange(sv.shape[0]) % m
+            # a slot's column-side state ranks the rows of its column block
+            _, slot_blocks = plan.workload.job_coord_batch(
+                np.asarray(state[4], np.int64))
+            stacks.append((state[2], state[3], slot_blocks))
+        for sv, sc, blocks in stacks:
+            sv, sc = np.asarray(sv), np.asarray(sc)
             rows = np.broadcast_to(
                 (blocks[:, None] * t + np.arange(t))[:, :, None], sv.shape)
             ok = (sc >= 0) & (rows < n_r)

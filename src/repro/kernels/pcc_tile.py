@@ -100,6 +100,30 @@ class EpilogueSpec:
         return vals
 
 
+def _block_dot(a, b):
+    """(t, l_blk) . (t, l_blk)^T on the MXU, as an f32 partial tile.
+
+    Integer operands (Kendall pair signs, or absmax-quantized rows)
+    accumulate exactly in int32 per block, then widen (exact: each block dot
+    is bounded by l_blk * 127^2).  f32 operands ask for f32 contract
+    precision: Mosaic's default rounds them to bf16, which on a v5e left
+    Pearson at GPL570 width 3e-4 from float64.  fp8 operands have no MXU
+    path and widen to f32 first; like bf16 they are exact in the default
+    bf16 pass."""
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        return jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+    precision = None
+    if a.dtype == jnp.float32:
+        precision = jax.lax.Precision.HIGHEST
+    elif a.dtype != jnp.bfloat16:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
             epilogue: Optional[EpilogueSpec], replica: bool = False,
             scaled: bool = False):
@@ -129,25 +153,7 @@ def _kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     ucol = ucol_ref[0] if replica else ucol_ref[...]
-    # (t, l_blk) . (t, l_blk)^T on the MXU.  Float operands accumulate in
-    # f32; int8 operands (Kendall pair signs, or absmax-quantized rows)
-    # accumulate exactly in int32 per block, then widen to the f32 tile
-    # accumulator (exact: each block dot is bounded by l_blk * 127^2).
-    if jnp.issubdtype(urow_ref.dtype, jnp.integer):
-        part = jax.lax.dot_general(
-            urow_ref[...],
-            ucol,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        ).astype(jnp.float32)
-    else:
-        part = jax.lax.dot_general(
-            urow_ref[...].astype(jnp.float32) if scaled else urow_ref[...],
-            ucol.astype(jnp.float32) if scaled else ucol,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-    out_ref[...] += part
+    out_ref[...] += _block_dot(urow_ref[...], ucol)
 
     # Dequantization and epilogue share ONE final-k block so their order is
     # structural (scales first, then div/clip) — never two racing pl.when's.
@@ -157,9 +163,9 @@ def _kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
         def _finalize():
             acc = out_ref[...]
             if scaled:
-                srow = srow_ref[0]
-                scol = scol_ref[0, 0] if replica else scol_ref[0]
-                acc = acc * (srow[:, None] * scol[None, :])
+                srow = srow_ref[0]                                # (t, 1)
+                scol = scol_ref[0, 0] if replica else scol_ref[0]  # (1, t)
+                acc = acc * (srow * scol)
             if epilogue is not None and not epilogue.is_identity():
                 acc = epilogue.apply(acc)
             out_ref[...] = acc
@@ -198,35 +204,38 @@ def _out_map(i, k, jstart_ref, *, m: int, total: int):
     return i, 0, 0
 
 
-# Scale index maps (quantized operands): the per-row scales are reshaped to
-# (m, t) so each tile pulls one (1, t) scale block.  They follow the same
-# tile-id bijection as their operand, but ignore the k axis (block col 0).
+# Scale index maps (quantized operands): the per-row scales are laid out as
+# an (m, t, 1) column for the rows and an (mc, 1, t) row for the columns, so
+# each tile pulls a (t, 1) and a (1, t) block whose last two dims equal the
+# array's (the TPU block rule) and whose product is the tile's outer
+# product.  They follow the same tile-id bijection as their operand, but
+# ignore the k axis.
 
 
 def _scale_row_map(i, k, jstart_ref, *, m: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
     y_t, _ = job_coord_f32(m, jt)
-    return y_t, 0
+    return y_t, 0, 0
 
 
 def _scale_col_map(i, k, jstart_ref, *, m: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
     _, x_t = job_coord_f32(m, jt)
-    return x_t, 0
+    return x_t, 0, 0
 
 
 def _scale_grid_row_map(i, k, jstart_ref, *, mc: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
-    return jt // mc, 0
+    return jt // mc, 0, 0
 
 
 def _scale_grid_col_map(i, k, jstart_ref, *, mc: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
-    return jt - (jt // mc) * mc, 0
+    return jt - (jt // mc) * mc, 0, 0
 
 
 # Replica-axis index maps (significance workload): the grid is
@@ -269,26 +278,26 @@ def _rep_scale_row_map(r, i, k, jstart_ref, *, m: int, total: int):
     del r, k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
     y_t, _ = job_coord_f32(m, jt)
-    return y_t, 0
+    return y_t, 0, 0
 
 
 def _rep_scale_col_map(r, i, k, jstart_ref, *, m: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
     _, x_t = job_coord_f32(m, jt)
-    return r, x_t, 0
+    return r, x_t, 0, 0
 
 
 def _rep_scale_grid_row_map(r, i, k, jstart_ref, *, mc: int, total: int):
     del r, k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
-    return jt // mc, 0
+    return jt // mc, 0, 0
 
 
 def _rep_scale_grid_col_map(r, i, k, jstart_ref, *, mc: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
-    return r, jt - (jt // mc) * mc, 0
+    return r, jt - (jt // mc) * mc, 0, 0
 
 
 @functools.partial(
@@ -419,8 +428,8 @@ def pcc_tiles(
             pl.BlockSpec((t, l_blk), row_map),
             pl.BlockSpec((t, l_blk), col_map),
         ]
-        scale_specs = [pl.BlockSpec((1, t), smaps[0]),
-                       pl.BlockSpec((1, t), smaps[1])]
+        scale_specs = [pl.BlockSpec((1, t, 1), smaps[0]),
+                       pl.BlockSpec((1, 1, t), smaps[1])]
         out_specs = pl.BlockSpec(
             (1, t, t), functools.partial(_out_map, m=m, total=total))
         out_shape = (pass_tiles, t, t)
@@ -432,8 +441,8 @@ def pcc_tiles(
             pl.BlockSpec((t, l_blk), row_map),
             pl.BlockSpec((1, t, l_blk), col_map),
         ]
-        scale_specs = [pl.BlockSpec((1, t), smaps[0]),
-                       pl.BlockSpec((1, 1, t), smaps[1])]
+        scale_specs = [pl.BlockSpec((1, t, 1), smaps[0]),
+                       pl.BlockSpec((1, 1, 1, t), smaps[1])]
         out_specs = pl.BlockSpec(
             (1, 1, t, t), functools.partial(_rep_out_map, m=m, total=total))
         out_shape = (replicas, pass_tiles, t, t)
@@ -442,15 +451,15 @@ def pcc_tiles(
     if scaled:
         # scales arrive per padded row (n_pad,) — or (R, cols_pad) for a
         # replica-stacked column operand — and are reshaped so each tile's
-        # scale block is one (.., 1, t) row of the (.., m, t) layout
+        # scale blocks are one (t, 1) column and one (.., 1, t) row
         in_specs = in_specs + scale_specs
-        srow2d = jnp.asarray(row_scale, jnp.float32).reshape(m, t)
+        srow = jnp.asarray(row_scale, jnp.float32).reshape(m, t, 1)
         cs = jnp.asarray(col_scale, jnp.float32)
         if replicas is None:
-            scol2d = cs.reshape(v.shape[0] // t, t)
+            scol = cs.reshape(v.shape[0] // t, 1, t)
         else:
-            scol2d = cs.reshape(replicas, v.shape[1] // t, t)
-        operands += [srow2d, scol2d]
+            scol = cs.reshape(replicas, v.shape[1] // t, 1, t)
+        operands += [srow, scol]
 
     out = pl.pallas_call(
         kernel,
@@ -476,16 +485,21 @@ def pcc_tiles(
 # instead of n^2/hosts of tiles (the CoMet trick, arXiv:1705.08213).
 #
 # The in-kernel selection replicates core/sinks.topk_merge_rows' canonical
-# order *exactly*: |value| descending, ties by ascending column — two stable
-# argsorts (secondary key first) are np.lexsort((col, -|v|)) — so per-host
-# partial states merge into results bit-identical to a single-host TopKSink.
+# order *exactly* — |value| descending, ties by ascending column — so
+# per-host partial states merge into results bit-identical to a single-host
+# TopKSink.  It is sort-free (the TPU compiler lowers no sort): kk rounds of
+# max-extraction, each a handful of lane reductions.
 #
-# State blocks are revisited across grid steps: the row state y(jt) is
-# non-decreasing within a pass (row-major tile order), so its revisits are
-# consecutive; the mirrored column state x(jt) is not monotonic, which is
-# read-modify-write-correct in interpret mode (this repo's execution mode —
-# see docs/architecture.md) but would need a revisit-ordering guarantee on
-# compiled TPU pipelines.
+# Row state blocks are revisited across grid steps, and that is safe on a
+# compiled pipeline only because the row block y(jt) is non-decreasing
+# within a pass (row-major tile order): a block's visits are consecutive, so
+# it stays resident in VMEM between them and is written back once.  Its
+# first visit loads the carried-in state explicitly (an output block is not
+# read from HBM).  The mirrored column side of triangular runs has no such
+# order — x(jt) revisits a block once per row above it — so it is not kept
+# as revisited state at all: every tile slot writes its own (t, kk)
+# column-side top-k, and the host merge (core/sinks.DeviceTopKSink) folds
+# the slots into their rows.
 
 
 def _tk_row_state_map(i, k, jstart_ref, *, m: int, total: int):
@@ -495,38 +509,62 @@ def _tk_row_state_map(i, k, jstart_ref, *, m: int, total: int):
     return y_t, 0, 0
 
 
-def _tk_col_state_map(i, k, jstart_ref, *, m: int, total: int):
-    del k
-    jt = jnp.minimum(jstart_ref[0] + i, total - 1)
-    _, x_t = job_coord_f32(m, jt)
-    return x_t, 0, 0
-
-
 def _tk_grid_row_state_map(i, k, jstart_ref, *, mc: int, total: int):
     del k
     jt = jnp.minimum(jstart_ref[0] + i, total - 1)
     return jt // mc, 0, 0
 
 
-def _topk_select(state_v, state_c, tile_v, tile_c, kk: int):
-    """Merge (t, t) tile candidates into (t, kk) state under the canonical
-    order.  Masked candidates carry column -1 (key -inf, value zeroed) and
-    are dropped again host-side, exactly like empty state slots."""
-    cand_v = jnp.concatenate(
-        [state_v, jnp.where(tile_c < 0, jnp.float32(0.0), tile_v)], axis=1)
-    cand_c = jnp.concatenate([state_c, tile_c], axis=1)
-    key = jnp.where(cand_c < 0, -jnp.inf, jnp.abs(cand_v))
-    p1 = jnp.argsort(cand_c, axis=1, stable=True)
-    key1 = jnp.take_along_axis(-key, p1, axis=1)
-    p2 = jnp.argsort(key1, axis=1, stable=True)
-    sel = jnp.take_along_axis(p1, p2, axis=1)[:, :kk]
-    return (jnp.take_along_axis(cand_v, sel, axis=1),
-            jnp.take_along_axis(cand_c, sel, axis=1))
+def _tk_slot_map(i, k, jstart_ref):
+    del k, jstart_ref
+    return i, 0, 0
 
 
-def _topk_kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
-                 epilogue: Optional[EpilogueSpec], kk: int, t: int,
-                 n_cols: int, symmetric: bool, mirror: bool, m: int,
+def _topk_select(parts, kk: int):
+    """Top-kk per row of the union of (values, columns) candidate arrays,
+    each (t, w), under the canonical order.  Masked candidates carry column
+    -1 and empty output slots are (0, -1), exactly like empty state slots;
+    both are dropped again host-side.
+
+    Each round takes the largest |value|, then the smallest column holding
+    it; candidate columns are unique per row, so that names one candidate,
+    which is then retired.  Once only masked candidates remain, every later
+    slot is empty.  A NaN value ranks below every empty slot in the
+    canonical order, so it never enters a top-k: it is masked here too."""
+    keys = [jnp.where((c < 0) | jnp.isnan(v), -jnp.inf, jnp.abs(v))
+            for v, c in parts]
+    rows = parts[0][0].shape[0]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (rows, kk), 1)
+    big = jnp.iinfo(jnp.int32).max
+
+    def round_(r, carry):
+        keys, out_v, out_c = carry
+        kmax = functools.reduce(jnp.maximum, [
+            jnp.max(key, axis=1, keepdims=True) for key in keys])
+        cmin = functools.reduce(jnp.minimum, [
+            jnp.min(jnp.where(key == kmax, c, big), axis=1, keepdims=True)
+            for key, (_, c) in zip(keys, parts)])
+        hits = [(key == kmax) & (c == cmin)
+                for key, (_, c) in zip(keys, parts)]
+        val = functools.reduce(jnp.maximum, [
+            jnp.max(jnp.where(h, v, -jnp.inf), axis=1, keepdims=True)
+            for h, (v, _) in zip(hits, parts)])
+        live = kmax > -jnp.inf
+        here = slot == r
+        out_v = jnp.where(here, jnp.where(live, val, 0.0), out_v)
+        out_c = jnp.where(here, jnp.where(live, cmin, -1), out_c)
+        keys = [jnp.where(h, -jnp.inf, key) for h, key in zip(hits, keys)]
+        return keys, out_v, out_c
+
+    _, out_v, out_c = jax.lax.fori_loop(
+        0, kk, round_, (keys, jnp.zeros((rows, kk), jnp.float32),
+                        jnp.full((rows, kk), -1, jnp.int32)))
+    return out_v, out_c
+
+
+def _topk_kernel(jstart_ref, urow_ref, ucol_ref, rv_in, rc_in, *rest,
+                 l_blocks: int, epilogue: Optional[EpilogueSpec], kk: int,
+                 t: int, n_cols: int, symmetric: bool, mirror: bool, m: int,
                  grid_cols: Optional[int], total: int):
     """pcc_tiles' accumulation (bit-identical f32 adds into a VMEM scratch)
     plus a final-k-step merge of the finished tile into per-row top-k state.
@@ -537,35 +575,35 @@ def _topk_kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
     never contribute candidates and per-(device, pass) states stay disjoint.
     """
     if mirror:
-        (_rv_in, _rc_in, _cv_in, _cc_in,
-         rv_out, rc_out, cv_out, cc_out, acc) = rest
+        rv_out, rc_out, cv_out, cc_out, acc = rest
     else:
-        _rv_in, _rc_in, rv_out, rc_out, acc = rest
+        rv_out, rc_out, acc = rest
     i = pl.program_id(0)
     k = pl.program_id(1)
+
+    def coords(jt):
+        if grid_cols is None:
+            return job_coord_f32(m, jt)
+        y = jt // grid_cols
+        return y, jt - y * grid_cols
+
+    jt_raw = jstart_ref[1] + i
+    valid = jt_raw < jstart_ref[2]
+    y_t, x_t = coords(jnp.minimum(jt_raw, total - 1))
+    y_prev, _ = coords(jnp.clip(jt_raw - 1, 0, total - 1))
 
     @pl.when(k == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    if jnp.issubdtype(urow_ref.dtype, jnp.integer):
-        part = jax.lax.dot_general(
-            urow_ref[...], ucol_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32).astype(jnp.float32)
-    else:
-        part = jax.lax.dot_general(
-            urow_ref[...], ucol_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    acc[...] += part
+    @pl.when((k == 0) & ((i == 0) | (y_prev != y_t)))
+    def _load_row_state():
+        # first visit of this row block in the launch: its consecutive
+        # visits keep it resident, so only this one reads the state in
+        rv_out[...] = rv_in[...]
+        rc_out[...] = rc_in[...]
 
-    jt_raw = jstart_ref[1] + i
-    valid = jt_raw < jstart_ref[2]
-    jt = jnp.minimum(jt_raw, total - 1)
-    if grid_cols is None:
-        y_t, x_t = job_coord_f32(m, jt)
-    else:
-        y_t = jt // grid_cols
-        x_t = jt - y_t * grid_cols
+    acc[...] += _block_dot(urow_ref[...], ucol_ref[...])
 
     def _final_tile():
         r = acc[...]
@@ -584,23 +622,30 @@ def _topk_kernel(jstart_ref, urow_ref, ucol_ref, *rest, l_blocks: int,
         bad = cols_g >= n_cols
         if symmetric:
             bad = bad | (y_t * t + rows_io == cols_g)
-        nv, nc = _topk_select(rv_out[0], rc_out[0], r,
-                              jnp.where(bad, -1, cols_g), kk)
+        nv, nc = _topk_select(
+            [(rv_out[0], rc_out[0]), (r, jnp.where(bad, -1, cols_g))], kk)
         rv_out[0] = nv
         rc_out[0] = nc
 
     if mirror:
         # off-diagonal tiles also rank row i as a neighbour of row j via the
-        # transposed tile; diagonal tiles already carry both orders
-        @pl.when(last & valid & (y_t != x_t))
+        # transposed tile (diagonal tiles already carry both orders); every
+        # slot writes its own column-side state, empty when it adds nothing
+        off_diag = valid & (y_t != x_t)
+
+        @pl.when(last & off_diag)
         def _merge_cols():
-            r = _final_tile()
             cols_g = y_t * t + cols_io
-            bad = cols_g >= n_cols
-            nv, nc = _topk_select(cv_out[0], cc_out[0], r.T,
-                                  jnp.where(bad, -1, cols_g), kk)
+            nv, nc = _topk_select(
+                [(_final_tile().T, jnp.where(cols_g >= n_cols, -1, cols_g))],
+                kk)
             cv_out[0] = nv
             cc_out[0] = nc
+
+        @pl.when(last & ~off_diag)
+        def _empty_cols():
+            cv_out[...] = jnp.zeros_like(cv_out)
+            cc_out[...] = jnp.full(cc_out.shape, -1, jnp.int32)
 
 
 @functools.partial(
@@ -636,14 +681,14 @@ def pcc_topk_tiles(
 
     kk: state capacity per row (>= the requested k); n_cols_valid masks
     padding columns; symmetric_problem additionally masks self-pairs.
-    Triangular runs (grid_cols=None) also maintain mirrored column-side
-    state, so a row's neighbours from tiles where it is the *column* block
-    are captured without ever materialising the transpose.
 
-    Returns (row_vals, row_cols) for grid workloads, plus
-    (col_vals, col_cols) for triangular ones — each (m, t, kk), value 0 /
-    column -1 marking empty slots.  Replica stacks and quantized scaled
-    operands are not supported (core/sinks.DeviceTopKSink gates on this).
+    Returns (row_vals, row_cols), each (m, t, kk), for grid workloads.
+    Triangular runs (grid_cols=None) also return (col_vals, col_cols), each
+    (pass_tiles, t, kk): slot i's column-side top-k — the neighbours its
+    tile gives the rows of its *column* block, read off the transposed tile
+    — so no transpose is ever materialised.  Value 0 / column -1 mark empty
+    slots.  Replica stacks and quantized scaled operands are not supported
+    (core/sinks.DeviceTopKSink gates on this).
     """
     n_pad, l_pad = u_pad.shape
     if n_pad % t or l_pad % l_blk:
@@ -669,7 +714,6 @@ def pcc_topk_tiles(
         row_map = functools.partial(_row_map, m=m, total=total)
         col_map = functools.partial(_col_map, m=m, total=total)
         rs_map = functools.partial(_tk_row_state_map, m=m, total=total)
-        cs_map = functools.partial(_tk_col_state_map, m=m, total=total)
     else:
         if v.shape[-1] != l_pad or v.shape[-2] != grid_cols * t:
             raise ValueError(
@@ -680,7 +724,6 @@ def pcc_topk_tiles(
         col_map = functools.partial(_grid_col_map, mc=grid_cols, total=total)
         rs_map = functools.partial(_tk_grid_row_state_map, mc=grid_cols,
                                    total=total)
-        cs_map = None
     l_blocks = l_pad // l_blk
 
     j0 = jnp.asarray(j_start, jnp.int32).reshape(())
@@ -694,26 +737,16 @@ def pcc_topk_tiles(
 
     state_spec = pl.BlockSpec((1, t, kk), rs_map)
     in_specs = [pl.BlockSpec((t, l_blk), row_map),
-                pl.BlockSpec((t, l_blk), col_map),
-                state_spec, pl.BlockSpec((1, t, kk), rs_map)]
-    out_specs = [state_spec, pl.BlockSpec((1, t, kk), rs_map)]
-    rv0 = jnp.zeros((m, t, kk), jnp.float32)
-    rc0 = jnp.full((m, t, kk), -1, jnp.int32)
-    operands = [starts, u_pad, v, rv0, rc0]
+                pl.BlockSpec((t, l_blk), col_map), state_spec, state_spec]
+    out_specs = [state_spec, state_spec]
     out_shape = [jax.ShapeDtypeStruct((m, t, kk), jnp.float32),
                  jax.ShapeDtypeStruct((m, t, kk), jnp.int32)]
-    # aliased state inputs initialise the revisited output blocks; indices
-    # count the scalar-prefetch operand (starts = 0)
-    aliases = {3: 0, 4: 1}
     if mirror:
-        col_state_spec = pl.BlockSpec((1, t, kk), cs_map)
-        in_specs += [col_state_spec, pl.BlockSpec((1, t, kk), cs_map)]
-        out_specs += [col_state_spec, pl.BlockSpec((1, t, kk), cs_map)]
-        operands += [jnp.zeros((m, t, kk), jnp.float32),
-                     jnp.full((m, t, kk), -1, jnp.int32)]
-        out_shape += [jax.ShapeDtypeStruct((m, t, kk), jnp.float32),
-                      jax.ShapeDtypeStruct((m, t, kk), jnp.int32)]
-        aliases.update({5: 2, 6: 3})
+        out_specs += [pl.BlockSpec((1, t, kk), _tk_slot_map)] * 2
+        out_shape += [jax.ShapeDtypeStruct((pass_tiles, t, kk), jnp.float32),
+                      jax.ShapeDtypeStruct((pass_tiles, t, kk), jnp.int32)]
+    operands = [starts, u_pad, v, jnp.zeros((m, t, kk), jnp.float32),
+                jnp.full((m, t, kk), -1, jnp.int32)]
 
     return pl.pallas_call(
         kernel,
@@ -726,7 +759,10 @@ def pcc_topk_tiles(
         ),
         out_shape=tuple(out_shape),
         interpret=interpret,
-        input_output_aliases=aliases,
+        # the empty state inputs initialise the row-state outputs, so row
+        # blocks no tile of the launch visits come back empty; indices
+        # count the scalar-prefetch operand (starts = 0)
+        input_output_aliases={3: 0, 4: 1},
     )(*operands)
 
 

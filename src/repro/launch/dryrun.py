@@ -19,26 +19,21 @@ the cache.
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-3b --shape train_4k [--multi-pod]
   python -m repro.launch.dryrun --all [--multi-pod] [--arch-filter moe]
-  python -m repro.launch.dryrun --pcc artificial_64k [--multi-pod]
 """
 
 import argparse
 import json
 import time
 import traceback
-from typing import Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs import get_config, list_archs
 from repro.launch.mesh import describe, make_production_mesh
 from repro.models import steps as model_steps
-from repro.models.config import SHAPES, cache_specs, input_specs
+from repro.models.config import SHAPES, input_specs
 from repro.models.registry import build_model
 from repro.models.sharding import make_policy
 from repro.optim import adamw
@@ -175,94 +170,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool,
     return rec
 
 
-def run_pcc(dataset: str, multi_pod: bool, save: bool = True) -> dict:
-    """Dry-run the paper's own workload: distributed triangular PCC."""
-    from repro.configs import lightpcc
-    from repro.core import tiling
-    from repro.core.distributed import tiles_per_device
-    from repro.kernels.pcc_tile import pcc_tiles
-
-    pcc_cfg = {c.name: c for t in lightpcc.TABLES.values()
-               for c in t}[dataset]
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    p = int(mesh.devices.size)
-    plan = tiling.TilePlan.create(pcc_cfg.n, pcc_cfg.l, pcc_cfg.t)
-    l_pad = -(-pcc_cfg.l // pcc_cfg.l_blk) * pcc_cfg.l_blk
-    per_dev = tiles_per_device(plan.total_tiles, p)
-    pass_tiles = min(per_dev, pcc_cfg.max_tiles_per_pass)
-    axes = tuple(mesh.axis_names)
-
-    # interpret=True: the CPU backend only lowers Pallas in interpret mode
-    # (the TPU launcher flips this off); the compiled SPMD program still
-    # proves the mesh/sharding plan, and kernel FLOPs are reported
-    # analytically below (exact for a GEMM tile kernel).
-    def device_fn(u_rep, j0):
-        rank = jnp.int32(0)
-        for ax in axes:
-            rank = rank * mesh.shape[ax] + jax.lax.axis_index(ax)
-        start = jnp.minimum(rank * per_dev + j0[0], plan.total_tiles - 1)
-        return pcc_tiles(u_rep, start, t=pcc_cfg.t, l_blk=pcc_cfg.l_blk,
-                         pass_tiles=pass_tiles, interpret=True)
-
-    fn = jax.jit(shard_map(
-        device_fn, mesh=mesh,
-        in_specs=(P(*([None] * 2)), P()),
-        out_specs=P(axes), check_vma=False))
-    u_spec = jax.ShapeDtypeStruct((plan.n_pad, l_pad), jnp.float32,
-                                  sharding=NamedSharding(mesh, P(None, None)))
-    j_spec = jax.ShapeDtypeStruct((1,), jnp.int32,
-                                  sharding=NamedSharding(mesh, P()))
-    label = f"lightpcc-{dataset}__allpairs__{'pod2' if multi_pod else 'pod1'}"
-    t0 = time.time()
-    lowered = fn.lower(u_spec, j_spec)
-    compiled = lowered.compile()
-    t_compile = time.time() - t0
-    rec = {
-        "label": label, "arch": f"lightpcc-{dataset}", "shape": "allpairs",
-        "kind": "pcc", "mesh": describe(mesh), "chips": p,
-        "n": pcc_cfg.n, "l": pcc_cfg.l, "t": pcc_cfg.t,
-        "tiles_total": plan.total_tiles, "tiles_per_device": per_dev,
-        "pass_tiles": pass_tiles, "compile_s": round(t_compile, 2),
-        "paper_unit_ops": lightpcc.flops(pcc_cfg),
-        # exact analytic kernel cost per device per pass (GEMM tiles):
-        # pass_tiles * t^2 * 2*l_pad FLOPs; operands read t*l_pad*2 per tile
-        "analytic_flops_per_dev":
-            pass_tiles * pcc_cfg.t * pcc_cfg.t * 2 * l_pad,
-        "analytic_hbm_bytes_per_dev":
-            pass_tiles * (2 * pcc_cfg.t * l_pad + pcc_cfg.t * pcc_cfg.t) * 4,
-    }
-    try:
-        ca = compiled.cost_analysis()
-        rec["cost"] = {k: float(v) for k, v in ca.items()
-                       if isinstance(v, (int, float)) and (
-                           "flops" in k or "bytes" in k)}
-    except Exception as e:
-        rec["cost"] = {"error": str(e)}
-    try:
-        ma = compiled.memory_analysis()
-        rec["memory"] = {
-            k: int(getattr(ma, k)) for k in dir(ma)
-            if k.endswith("_size_in_bytes") and not k.startswith("_")}
-    except Exception as e:
-        rec["memory"] = {"error": str(e)}
-    stats = hlo.collective_stats(compiled.as_text())
-    rec["collectives"] = {"bytes_by_kind": stats.bytes_by_kind,
-                          "count_by_kind": stats.count_by_kind,
-                          "total_bytes": stats.total_bytes}
-    print(f"[dryrun] {label}: compile={t_compile:.1f}s "
-          f"flops={rec['cost'].get('flops', float('nan')):.3e}")
-    if save:
-        os.makedirs(RESULTS_DIR, exist_ok=True)
-        with open(os.path.join(RESULTS_DIR, label + ".json"), "w") as f:
-            json.dump(rec, f, indent=1)
-    return rec
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)
-    ap.add_argument("--pcc", default=None, help="lightpcc dataset name")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--all", action="store_true")
@@ -272,10 +183,7 @@ def main() -> None:
 
     meshes = [args.multi_pod] if not args.both_meshes else [False, True]
     jobs = []
-    if args.pcc:
-        for mp in meshes:
-            jobs.append(("pcc", args.pcc, mp))
-    elif args.all:
+    if args.all:
         for arch in list_archs():
             if args.arch_filter and args.arch_filter not in arch:
                 continue
@@ -285,24 +193,20 @@ def main() -> None:
                     jobs.append((arch, shape, mp))
     else:
         if not (args.arch and args.shape):
-            ap.error("--arch and --shape (or --all / --pcc) required")
+            ap.error("--arch and --shape (or --all) required")
         for mp in meshes:
             jobs.append((args.arch, args.shape, mp))
 
     os.makedirs(RESULTS_DIR, exist_ok=True)
     failures = []
     for arch, shape, mp in jobs:
-        label = (f"lightpcc-{shape}__allpairs__" if arch == "pcc"
-                 else f"{arch}__{shape}__") + ("pod2" if mp else "pod1")
+        label = f"{arch}__{shape}__" + ("pod2" if mp else "pod1")
         path = os.path.join(RESULTS_DIR, label + ".json")
         if os.path.exists(path) and not args.force:
             print(f"[dryrun] {label}: cached, skipping")
             continue
         try:
-            if arch == "pcc":
-                run_pcc(shape, mp)
-            else:
-                run_cell(arch, shape, mp)
+            run_cell(arch, shape, mp)
         except Exception as e:
             failures.append((label, repr(e)))
             print(f"[dryrun] {label}: FAILED {e!r}")

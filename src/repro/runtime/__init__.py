@@ -1,4 +1,5 @@
-"""repro.runtime — elastic re-meshing, fault injection, stragglers, HLO.
+"""repro.runtime — elastic re-meshing, fault injection, stragglers, HLO,
+the compilation cache.
 
 Submodules and the re-exported train-loop names resolve lazily (PEP 562):
 ``repro.core`` imports the fault-injection harness (runtime/faults.py)
@@ -7,7 +8,8 @@ both create a cycle (faults <- core.sinks <- core <- elastic <- core.plan)
 and drag the whole train-loop stack into every engine import.
 """
 
-_SUBMODULES = ("elastic", "faults", "hlo", "straggler", "train_loop")
+_SUBMODULES = ("compile_cache", "elastic", "faults", "hlo", "straggler",
+               "train_loop")
 _TRAIN_LOOP_NAMES = ("TrainLoop", "LoopConfig", "FailureInjected")
 
 __all__ = [*_SUBMODULES, *_TRAIN_LOOP_NAMES]

@@ -29,11 +29,11 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
-from repro.compat import shard_map
 from repro.data.synthetic import TokenStreamSpec, batch_at
 from repro.models import steps as model_steps
 from repro.models.config import ModelConfig

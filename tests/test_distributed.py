@@ -1,8 +1,8 @@
 """Distributed drivers on 8 simulated devices.
 
 These run in a SUBPROCESS with XLA_FLAGS=--xla_force_host_platform_device_count=8
-so the main pytest process keeps seeing 1 device (per the project rule that
-only dryrun.py forces a device count).
+(4 where stated) so the main pytest process keeps seeing 1 device (per the
+project rule that only dryrun.py forces a device count).
 """
 
 import os
@@ -17,9 +17,11 @@ _ENV = dict(os.environ,
             PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
-def _run(body: str):
+def _run(body: str, devices: int = 8):
     code = textwrap.dedent(body)
-    res = subprocess.run([sys.executable, "-c", code], env=_ENV,
+    env = dict(_ENV, XLA_FLAGS=f"--xla_force_host_platform_device_count="
+                               f"{devices}")
+    res = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, f"stdout:\n{res.stdout}\nstderr:\n{res.stderr}"
     return res.stdout
@@ -89,7 +91,7 @@ def test_sharded_streaming_bit_identical_to_materializing_path():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core import measures
         from repro.core.allpairs import prepare, scatter_tiles, symmetrize
         from repro.core.distributed import (allpairs_pcc_sharded,
@@ -256,6 +258,46 @@ def test_sharded_corr_facade_all_workloads():
         assert np.abs(got - mref).max() < 1e-5
         print("OK")
     """)
+
+
+@pytest.mark.parametrize("workload", ["symmetric", "shard_u", "rectangular",
+                                      "device_topk"])
+def test_corr_mesh_explicit_and_auto_axes(workload):
+    """corr(mesh=) takes the Explicit-axis meshes jax.make_mesh builds by
+    default and Auto-axis ones alike, bit-identical to one device, on 4
+    devices; a dense result lives replicated on the mesh, not on one
+    device."""
+    _run(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
+        from repro.core.api import corr
+        from repro.core.sinks import DeviceTopKSink
+        rng = np.random.default_rng(12)
+        x = jnp.asarray(rng.standard_normal((44, 20)).astype(np.float32))
+        y = jnp.asarray(rng.standard_normal((27, 20)).astype(np.float32))
+        kw = dict(t=8, l_blk=8)
+        args, extra = {{
+            "symmetric": ((x,), {{}}),
+            "shard_u": ((x,), dict(shard_u=True)),
+            "rectangular": ((x, y), {{}}),
+            "device_topk": ((x,), dict(sink=DeviceTopKSink(5))),
+        }}["{workload}"]
+        local = corr(*args, **kw, **{{k: v for k, v in extra.items()
+                                      if k != "shard_u"}})
+        for axis_type in (AxisType.Explicit, AxisType.Auto):
+            mesh = jax.make_mesh((4,), ("d",), axis_types=(axis_type,))
+            got = corr(*args, **kw, **extra, mesh=mesh,
+                       max_tiles_per_pass=3)
+            if isinstance(got, dict):
+                for key in ("indices", "values"):
+                    np.testing.assert_array_equal(got[key], local[key])
+                continue
+            assert got.sharding.is_fully_replicated, got.sharding
+            assert got.sharding.device_set == set(mesh.devices.flat)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(local))
+        print("OK")
+    """, devices=4)
 
 
 @pytest.mark.slow
@@ -444,9 +486,9 @@ def test_compressed_psum_shard_map():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.optim.compression import compressed_psum
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = jax.make_mesh((8,), ("d",), axis_types=(jax.sharding.AxisType.Auto,))
         rng = np.random.default_rng(0)
         g_all = jnp.asarray(rng.standard_normal((8, 64)).astype(np.float32))
 

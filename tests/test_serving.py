@@ -112,6 +112,27 @@ def test_plan_cache_bounded_lru_eviction():
         PlanCache(capacity=0)
 
 
+def test_transform_cache_evicts_inside_its_own_locked_section():
+    """A cached array's death callback can run while its thread holds the
+    cache lock (a garbage collection triggered by an allocation there);
+    the eviction must not deadlock on that lock."""
+    cache = api.TransformCache(capacity=4)
+    held = [jnp.ones((8, 8), jnp.float32)]
+    cache.prepared(held[0], measures.PEARSON, None, T, LBLK,
+                   build=lambda: held[0] + 1)
+    assert len(cache) == 1
+
+    def drop_under_lock():
+        with cache._lock:
+            held.pop()      # last reference: the callback evicts right here
+
+    worker = threading.Thread(target=drop_under_lock, daemon=True)
+    worker.start()
+    worker.join(timeout=10)
+    assert not worker.is_alive(), "eviction deadlocked on the cache lock"
+    assert len(cache) == 0
+
+
 def test_plan_cache_serves_unregistered_custom_measures():
     """corr() accepts bare Measure objects; serving must too — the spec
     carries the resolved object, so an unregistered measure builds fine
