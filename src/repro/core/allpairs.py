@@ -37,7 +37,10 @@ should call allpairs() directly.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
+import functools
+import threading
 import warnings
 from typing import Iterator, Optional, Tuple
 
@@ -57,6 +60,7 @@ from repro.core.sinks import (DenseSink, TileSink, place_tiles_host,
 from repro.kernels.pcc_tile import (DEFAULT_LBLK, DEFAULT_TILE, pcc_tiles,
                                     pcc_topk_tiles)
 from repro.runtime import faults
+from repro.runtime.tracing import span
 
 Array = jax.Array
 
@@ -98,6 +102,52 @@ def prepare(x: Array, *, t: int = DEFAULT_TILE, l_blk: int = DEFAULT_LBLK,
             u = u.astype(eplan.compute_dtype)
         return pad_operands(u, t, l_blk), eplan.tile
     return eplan.prepare(x), eplan.tile
+
+
+# ---------------------------------------------------------------------------
+# Executor counters, and the call id the trace spans of one corr() share
+# ---------------------------------------------------------------------------
+
+_STATS_LOCK = threading.Lock()
+_STATS = {"calls": 0, "passes": 0, "mesh_programs_built": 0}
+# the corr() call this thread is inside (0 outside any): per thread, since
+# serving dispatches from its own thread while user threads call corr()
+_CALL = contextvars.ContextVar("repro_call", default=0)
+
+
+def _count(key: str) -> int:
+    with _STATS_LOCK:
+        _STATS[key] += 1
+        return _STATS[key]
+
+
+def executor_stats() -> dict:
+    """Process-wide executor counts: ``calls`` (corr() calls),
+    ``passes`` (pass launches dispatched) and ``mesh_programs_built``
+    (shard_map pass programs the mesh executor built)."""
+    with _STATS_LOCK:
+        return dict(_STATS)
+
+
+def current_call() -> int:
+    """The id of the corr() call this thread is inside, else 0."""
+    return _CALL.get()
+
+
+def traced_call(fn):
+    """Count each call of `fn` in ``executor_stats()["calls"]`` and run it
+    under a ``repro.corr`` span whose ``call`` id every span inside it
+    carries."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        call = _count("calls")
+        token = _CALL.set(call)
+        try:
+            with span("corr", call=call):
+                return fn(*args, **kwargs)
+        finally:
+            _CALL.reset(token)
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -188,15 +238,18 @@ def _local_launches(plan: ExecutionPlan, u_pad: Array,
             continue
         faults.check("pass_launch")
         lo = plan.pass_offset(k)
+        _count("passes")
         if state_k is not None:
-            buf = launch_topk_tiles(plan, u_pad, lo, plan.total_tiles,
-                                    launch, state_k, v=v_pad,
-                                    grid_cols=grid_cols)
+            with span("launch", call=current_call(), **{"pass": k}):
+                buf = launch_topk_tiles(plan, u_pad, lo, plan.total_tiles,
+                                        launch, state_k, v=v_pad,
+                                        grid_cols=grid_cols)
             ids = np.arange(lo, lo + launch, dtype=np.int64)
             yield k, ids, _with_slot_ids(buf, ids), None, None
             continue
-        buf = launch_tiles(plan, u_pad, lo, launch, v=v_pad,
-                           grid_cols=grid_cols)
+        with span("launch", call=current_call(), **{"pass": k}):
+            buf = launch_tiles(plan, u_pad, lo, launch, v=v_pad,
+                               grid_cols=grid_cols)
         if not plan.fused and plan.measure.epilogue is not None:
             buf = plan.measure.epilogue(buf, plan.l)
         # local launches are exact-sized: every slot is valid
@@ -324,6 +377,7 @@ def _mesh_launches(plan: ExecutionPlan, u_pad: Array, mesh: Mesh,
             out_spec = P(axes)
         fns[launch] = shard_map(device_fn, mesh=mesh, in_specs=specs,
                                 out_specs=out_spec, check_vma=False)
+        _count("mesh_programs_built")
         return fns[launch]
 
     for k, launch in list(enumerate(plan.launch_sizes))[start_pass:]:
@@ -335,7 +389,10 @@ def _mesh_launches(plan: ExecutionPlan, u_pad: Array, mesh: Mesh,
                 + ((v_in,) if v_in is not None else ())
                 + ((s_row_in, s_col_in) if has_s else ())
                 + (off,))
-        buf = pass_fn(launch)(*args)
+        _count("passes")
+        # spans the shard_map's trace, lowering and cache load too
+        with span("launch", call=current_call(), **{"pass": k}):
+            buf = pass_fn(launch)(*args)
         if state_k is not None:
             # state stacks carry their own validity guard: no clamped-slot
             # selection to resolve, and ids are the pass's true tile set
@@ -772,6 +829,7 @@ allpairs_similarity_streamed = allpairs_pcc_streamed
 __all__ = [
     "allpairs",
     "execute_plan",
+    "executor_stats",
     "launch_tiles",
     "launch_topk_tiles",
     "run_sink",

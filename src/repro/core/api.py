@@ -48,12 +48,14 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import measures
-from repro.core.allpairs import _stream, execute_plan, run_sink
+from repro.core.allpairs import (_stream, current_call, execute_plan,
+                                 executor_stats, run_sink, traced_call)
 from repro.core.lru import LruStatsCache
 from repro.core.plan import ExecutionPlan, pad_operands
 from repro.core.significance import PermutationSpec, run_significance
 from repro.core.sinks import HostSink, TileSink
 from repro.kernels.pcc_tile import DEFAULT_LBLK, DEFAULT_TILE
+from repro.runtime.tracing import span
 
 Array = jax.Array
 MaskLike = Union[None, str, np.ndarray, Array, Tuple]
@@ -149,11 +151,12 @@ def prepared_operand(plan: ExecutionPlan, x: Array, *,
         raise ValueError(
             f"operand shape {tuple(x.shape)} does not match plan "
             f"(rows={rows}, l={plan.l})")
-    if not cacheable:
-        return plan._prepare_one(x)
-    c = cache if cache is not None else _PREPARED
-    return c.prepared(x, plan.measure, plan.compute_dtype, plan.t, plan.l_blk,
-                      build=lambda: plan._prepare_one(x))
+    with span("prepare", call=current_call()):
+        if not cacheable:
+            return plan._prepare_one(x)
+        c = cache if cache is not None else _PREPARED
+        return c.prepared(x, plan.measure, plan.compute_dtype, plan.t,
+                          plan.l_blk, build=lambda: plan._prepare_one(x))
 
 
 def clear_prepared_cache() -> None:
@@ -264,6 +267,7 @@ class PairwiseProblem:
         return cls(x=x, y=y, measure=meas, mask_x=mask_x, mask_y=mask_y)
 
 
+@traced_call
 def corr(
     x: Array,
     y: Optional[Array] = None,
@@ -499,5 +503,6 @@ __all__ = [
     "TransformCache",
     "prepared_operand",
     "prepared_cache_stats",
+    "executor_stats",
     "clear_prepared_cache",
 ]

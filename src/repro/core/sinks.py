@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.core import mapping
 from repro.core.plan import ExecutionPlan
 from repro.runtime import faults
+from repro.runtime.tracing import span
 
 Array = jax.Array
 
@@ -212,15 +213,23 @@ class DenseSink(TileSink):
     workloads (nothing to mirror)."""
 
     def open(self, plan: ExecutionPlan) -> None:
+        from repro.core.allpairs import current_call  # lazy: it imports us
         super().open(plan)
         self.r_pad = None  # allocated where the first pass's tiles live
+        self._call = current_call()  # trace span ids: the call, ...
+        self._passes = 0             # ... and the pass, in arrival order
 
     def _scatter(self, ids: np.ndarray, tiles: Array) -> None:
-        if self.r_pad is None:
-            self.r_pad = jnp.zeros((self.plan.n_pad, self.plan.col_pad),
-                                   jnp.float32, device=output_sharding(tiles))
-        ys, xs = self.plan.workload.job_coord_batch(np.asarray(ids))
-        self.r_pad = scatter_tiles_at(self.r_pad, tiles, ys, xs, self.plan.t)
+        with span("sink.scatter", call=self._call,
+                  **{"pass": self._passes}):
+            if self.r_pad is None:
+                self.r_pad = jnp.zeros((self.plan.n_pad, self.plan.col_pad),
+                                       jnp.float32,
+                                       device=output_sharding(tiles))
+            ys, xs = self.plan.workload.job_coord_batch(np.asarray(ids))
+            self.r_pad = scatter_tiles_at(self.r_pad, tiles, ys, xs,
+                                          self.plan.t)
+        self._passes += 1
 
     def consume(self, ids: np.ndarray, tiles: Array) -> None:
         self._scatter(ids, tiles)
@@ -236,18 +245,21 @@ class DenseSink(TileSink):
         self._scatter(padded_ids, tiles)
 
     def result(self) -> Array:
-        if self.plan.workload.needs_symmetrize:
-            r = symmetrize(self.r_pad, self.plan.n)
-        else:
-            r = self.r_pad[: self.plan.n_rows, : self.plan.n_cols]
-        # Fused runs leave the kernel fully finalised (epilogue + clip).
-        # Unfused runs had the epilogue applied on the pass stream; only the
-        # bounded-measure clip remains — elementwise, so applying it after
-        # symmetrise is bit-identical to the historical order.
-        meas = self.plan.measure
-        if not self.plan.fused and self.plan.clip and meas.clip is not None:
-            r = jnp.clip(r, *meas.clip)
-        return r
+        with span("sink.symmetrize", call=self._call):
+            if self.plan.workload.needs_symmetrize:
+                r = symmetrize(self.r_pad, self.plan.n)
+            else:
+                r = self.r_pad[: self.plan.n_rows, : self.plan.n_cols]
+            # Fused runs leave the kernel fully finalised (epilogue +
+            # clip).  Unfused runs had the epilogue applied on the pass
+            # stream; only the bounded-measure clip remains — elementwise,
+            # so applying it after symmetrise is bit-identical to the
+            # historical order.
+            meas = self.plan.measure
+            if (not self.plan.fused and self.plan.clip
+                    and meas.clip is not None):
+                r = jnp.clip(r, *meas.clip)
+            return r
 
 
 def _id_intervals(ids: np.ndarray) -> List[List[int]]:

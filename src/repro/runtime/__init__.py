@@ -1,5 +1,5 @@
 """repro.runtime — elastic re-meshing, fault injection, stragglers, HLO,
-the compilation cache.
+the compilation cache, trace spans.
 
 Submodules and the re-exported train-loop names resolve lazily (PEP 562):
 ``repro.core`` imports the fault-injection harness (runtime/faults.py)
@@ -9,7 +9,7 @@ and drag the whole train-loop stack into every engine import.
 """
 
 _SUBMODULES = ("compile_cache", "elastic", "faults", "hlo", "straggler",
-               "train_loop")
+               "tracing", "train_loop")
 _TRAIN_LOOP_NAMES = ("TrainLoop", "LoopConfig", "FailureInjected")
 
 __all__ = [*_SUBMODULES, *_TRAIN_LOOP_NAMES]
