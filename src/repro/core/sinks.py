@@ -116,10 +116,17 @@ class TileSink(abc.ABC):
         """Finalise and return the run's output."""
 
 
-def _scatter_tiles_device(r_pad: Array, tiles: Array, coords: Array) -> Array:
+def _scatter_tiles_device(r_pad: Array, tiles: Array, coords: Array,
+                          placement: Optional[NamedSharding] = None) -> Array:
     """One batched scatter of (P, t, t) tiles into (n_pad, n_pad) at the
     (row, col) starts in coords (P, 2) — replaces the serial scan of
-    dynamic_update_slice (P sequential HLO ops) with a single scatter."""
+    dynamic_update_slice (P sequential HLO ops) with a single scatter.
+
+    With a mesh `placement` the mesh-sharded tiles are first resharded to
+    it inside this program: an XLA all-gather between the chips, never a
+    copy through the host."""
+    if placement is not None:
+        tiles = jax.sharding.reshard(tiles, placement)
     dnums = jax.lax.ScatterDimensionNumbers(
         update_window_dims=(1, 2),
         inserted_window_dims=(),
@@ -129,7 +136,8 @@ def _scatter_tiles_device(r_pad: Array, tiles: Array, coords: Array) -> Array:
                            indices_are_sorted=False, unique_indices=False)
 
 
-_scatter_tiles_device = jax.jit(_scatter_tiles_device)
+_scatter_tiles_device = jax.jit(_scatter_tiles_device,
+                                static_argnames=("placement",))
 
 
 def output_sharding(tiles) -> Optional[NamedSharding]:
@@ -137,11 +145,12 @@ def output_sharding(tiles) -> Optional[NamedSharding]:
     the mesh of mesh-sharded tiles, else None (the default device).
 
     Every device of the mesh then holds the whole padded matrix, and each
-    pass's (p * launch, t, t) buffer is all-gathered before the scatter —
-    the placement XLA picks for a scatter of batch-sharded updates anyway
-    (a row-sharded output would all-gather the matrix itself), now stated
-    so that meshes with Explicit axes (``jax.make_mesh``'s default) accept
-    it and no run funnels every shard onto one device."""
+    pass's (p * launch, t, t) buffer is all-gathered over the chips' links
+    inside the scatter program (scatter_tiles_at) — the placement XLA
+    picks for a scatter of batch-sharded updates anyway (a row-sharded
+    output would all-gather the matrix itself), now stated so that meshes
+    with Explicit axes (``jax.make_mesh``'s default) accept it and no run
+    funnels every shard onto one device."""
     sharding = getattr(tiles, "sharding", None)
     if isinstance(sharding, NamedSharding):
         return NamedSharding(sharding.mesh, PartitionSpec())
@@ -153,14 +162,12 @@ def scatter_tiles_at(r_pad: Array, tiles: Array, ys: np.ndarray,
     """Scatter (t, t) tiles into r_pad at tile coordinates (ys, xs) via one
     batched device scatter.  Workload-agnostic: callers invert ids with
     whichever bijection numbers their jobs.  Mesh-sharded tiles land in a
-    mesh-replicated r_pad (see output_sharding)."""
+    mesh-replicated r_pad (see output_sharding): the compiled scatter
+    all-gathers them first, one program per sharding and shape."""
     coords = jnp.stack([jnp.asarray(ys * t, jnp.int32),
                         jnp.asarray(xs * t, jnp.int32)], axis=1)
-    placement = output_sharding(tiles)
-    if placement is not None:
-        r_pad, tiles, coords = jax.device_put((r_pad, tiles, coords),
-                                              placement)
-    return _scatter_tiles_device(r_pad, tiles.astype(r_pad.dtype), coords)
+    return _scatter_tiles_device(r_pad, tiles.astype(r_pad.dtype), coords,
+                                 placement=output_sharding(tiles))
 
 
 def scatter_tiles(r_pad: Array, tiles: Array, ids: np.ndarray, t: int,
@@ -222,10 +229,15 @@ class DenseSink(TileSink):
     def _scatter(self, ids: np.ndarray, tiles: Array) -> None:
         with span("sink.scatter", call=self._call,
                   **{"pass": self._passes}):
+            placement = output_sharding(tiles)
             if self.r_pad is None:
                 self.r_pad = jnp.zeros((self.plan.n_pad, self.plan.col_pad),
-                                       jnp.float32,
-                                       device=output_sharding(tiles))
+                                       jnp.float32, device=placement)
+            elif placement is not None and getattr(
+                    self.r_pad.sharding, "mesh", None) != placement.mesh:
+                # The mesh shrank under recovery (rebind): copy the
+                # replicated matrix onto the survivors, chip to chip.
+                self.r_pad = jax.device_put(self.r_pad, placement)
             ys, xs = self.plan.workload.job_coord_batch(np.asarray(ids))
             self.r_pad = scatter_tiles_at(self.r_pad, tiles, ys, xs,
                                           self.plan.t)

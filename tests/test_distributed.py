@@ -300,6 +300,68 @@ def test_corr_mesh_explicit_and_auto_axes(workload):
     """, devices=4)
 
 
+@pytest.mark.parametrize("axis_type", ["Explicit", "Auto"])
+@pytest.mark.parametrize("workload", ["symmetric", "rectangular"])
+def test_mesh_dense_sink_gathers_on_device(workload, axis_type):
+    """A dense corr(mesh=) moves each pass's mesh-sharded tiles to the
+    replicated result inside the compiled scatter: no array is fetched to
+    the host in DenseSink._scatter (full and clamped passes alike), the
+    scatter program holds an all-gather, and an equal, newly built mesh
+    reuses that program."""
+    _run(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax._src.array import ArrayImpl
+        from jax.sharding import AxisType
+        import repro.core.sinks as sinks
+        from repro.core.api import corr
+
+        fetched = {{"inside": False, "n": 0}}
+        value = ArrayImpl._value
+        def counted(self):
+            fetched["n"] += fetched["inside"]
+            return value.fget(self)
+        ArrayImpl._value = property(counted)
+        scatter = sinks.DenseSink._scatter
+        def watched(self, ids, tiles):
+            fetched["inside"] = True
+            try:
+                scatter(self, ids, tiles)
+            finally:
+                fetched["inside"] = False
+        sinks.DenseSink._scatter = watched
+        program = sinks._scatter_tiles_device
+        seen = []
+        def recorded(*args, **kw):
+            seen.append((args, kw))
+            return program(*args, **kw)
+        sinks._scatter_tiles_device = recorded
+
+        rng = np.random.default_rng(14)
+        x = jnp.asarray(rng.standard_normal((44, 20)).astype(np.float32))
+        y = jnp.asarray(rng.standard_normal((27, 20)).astype(np.float32))
+        args = {{"symmetric": (x,), "rectangular": (x, y)}}["{workload}"]
+        axis_types = (AxisType.{axis_type},)
+        def solve():
+            mesh = jax.make_mesh((4,), ("d",), axis_types=axis_types)
+            return corr(*args, t=8, l_blk=8, mesh=mesh,
+                        max_tiles_per_pass=3)
+        got = solve()
+        assert got.sharding.is_fully_replicated, got.sharding
+        assert len(seen) > 1, len(seen)  # a full and a clamped pass
+        assert fetched["n"] == 0, fetched
+        for args_, kw in seen:
+            assert kw["placement"] is not None
+            text = program.lower(*args_, **kw).compile().as_text()
+            assert "all-gather" in text
+        built = program._cache_size()
+        again = solve()
+        assert program._cache_size() == built
+        assert fetched["n"] == 0, fetched
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+        print("OK")
+    """, devices=4)
+
+
 @pytest.mark.slow
 def test_pjit_train_matches_single_device_loss():
     """The sharded train step computes the same loss as unsharded."""
@@ -442,6 +504,47 @@ def test_multihost_sharded_sink_and_topk_bit_identical():
             d2, host=1, n_hosts=2, resume=True), mesh=mesh)
         assert r["complete"]
         np.testing.assert_array_equal(assemble(d2), ref)
+        print("OK")
+    """)
+
+
+def test_dense_sink_survives_device_loss():
+    """A dense run on a mesh that loses a device after its first passes
+    landed (8 -> 7 shrink): the replicated result moves onto the
+    survivors' mesh and the output is bit-identical to one device."""
+    _run("""
+        import jax, jax.numpy as jnp, numpy as np
+        import repro.core.sinks as sinks
+        from repro.core.plan import ExecutionPlan
+        from repro.core.allpairs import execute_plan
+        from repro.runtime.faults import FaultPlan, RetryPolicy
+
+        held = []  # devices holding the result after each scatter
+        scatter = sinks.DenseSink._scatter
+        def watched(self, ids, tiles):
+            scatter(self, ids, tiles)
+            held.append(len(self.r_pad.sharding.device_set))
+        sinks.DenseSink._scatter = watched
+
+        mesh = jax.make_mesh((8,), ("d",))
+        rng = np.random.default_rng(4)
+        x = jnp.asarray(rng.normal(size=(96, 16)).astype(np.float32))
+        plan1 = ExecutionPlan.create(96, 16, t=8, l_blk=8,
+                                     max_tiles_per_pass=4)
+        ref = np.asarray(execute_plan(plan1, plan1.prepare(x),
+                                      sink=sinks.DenseSink()))
+        plan = ExecutionPlan.create(96, 16, t=8, l_blk=8, p=8,
+                                    max_tiles_per_pass=1)
+        held.clear()
+        pol = RetryPolicy(sleep=lambda s: None)
+        with FaultPlan.single("pass_launch", "device_loss", at=3).armed():
+            got = execute_plan(plan, plan.prepare(x), sink=sinks.DenseSink(),
+                               mesh=mesh, recovery=pol)
+        assert [e["action"] for e in pol.log] == ["shrink_mesh"]
+        assert held[0] == 8 and held[-1] == 7, held
+        assert len(got.sharding.device_set) == 7 and \\
+            got.sharding.is_fully_replicated, got.sharding
+        np.testing.assert_array_equal(np.asarray(got), ref)
         print("OK")
     """)
 
